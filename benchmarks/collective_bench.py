@@ -63,7 +63,6 @@ def run(report):
 
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from repro.collective_exec import build_allreduce_program
@@ -80,8 +79,9 @@ def run(report):
         want = jnp.broadcast_to(x.sum(0), (n, 4))
         for kind in ALLREDUCE_KINDS:
             pc = PhaserCollective(n, "data", kind=kind)
-            f = shard_map(pc.all_reduce, mesh=mesh, in_specs=P("data"),
-                          out_specs=P("data"))
+            f = jax.shard_map(pc.all_reduce, mesh=mesh,
+                              in_specs=P("data"), out_specs=P("data"),
+                              check_vma=False)
             got = f(x)
             rows.append({"schedule": kind, "devices": n,
                          "allclose_vs_psum": bool(jnp.allclose(got,

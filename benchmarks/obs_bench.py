@@ -184,7 +184,7 @@ def run_case(label: str) -> dict:
 
 def _spawn_case(label: str):
     """One attempt in a fresh interpreter; None on deadlock/timeout."""
-    env = {**os.environ,
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",     # host-mesh rehearsal
            "XLA_FLAGS": "--xla_force_host_platform_device_count=12"}
     try:
         out = subprocess.run(

@@ -1,11 +1,14 @@
 import os
+# host-mesh rehearsals: every bench here runs on the host CPU, never on
+# an accelerator; its timings are XLA:CPU numbers, not device numbers
+os.environ["JAX_PLATFORMS"] = "cpu"
 if "XLA_FLAGS" not in os.environ:
     # collective_bench checks schedule equivalence on the host mesh;
     # pipeline_bench needs 12 devices for the 2-stage x 6-wide
     # interleaved-vs-wave-sync comparison
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=12"
 
-"""Benchmark runner: one table per paper claim.
+"""Benchmark runner: one table per paper claim, on a host-CPU mesh.
 
   PYTHONPATH=src python -m benchmarks.run [--only complexity,...]
 """
@@ -24,7 +27,8 @@ class Report:
 
     def table(self, title, rows, note=None):
         self.n += 1
-        print(f"\n== [{self.n}] {title} ==")
+        print(f"\n== [{self.n}] {title} (host CPU rehearsal, not device "
+              "numbers) ==")
         if not rows:
             print("  (empty)")
             return

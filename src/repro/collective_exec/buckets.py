@@ -22,7 +22,9 @@ finalize earliest, so a pipelined executor can start syncing group 0
 while the backward pass is still producing the later groups. Each group
 is padded to a whole number of buckets independently, which keeps every
 group's sub-buffer a standalone ``(g_buckets, bucket_elems)`` collective
-operand with no dataflow dependency on the other groups' leaves.
+operand with no dataflow dependency on the other groups' leaves. Each
+group's bucket count is a multiple of 8, the combine kernel's block
+height, so the full buffer and every group's sub-buffer tile alike.
 
 **Per-layer scan-slice sub-groups** (``block_groups=K``): the backward
 scan over the stacked blocks finalizes the stacked grad ROWS from the
@@ -46,7 +48,7 @@ from typing import Any, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..kernels.bucket_combine import MAX_BUCKET_BYTES
+from ..kernels.bucket_combine import MAX_BUCKET_BYTES, SUBLANES
 
 LANES = 128                        # TPU lane width: rows stay tile-aligned
 DEFAULT_BUCKET_ELEMS = 1 << 16     # 256 KiB f32 rows
@@ -332,7 +334,9 @@ def make_layout(tree, *, bucket_elems: int = None,
                     for j in range(glo, ghi))
         if g == len(group_leaves) - 1:
             elems += 1                        # alive flag rides the tail
-        group_buckets.append(max(1, -(-elems // bucket_elems)))
+        # whole kernel blocks: every group and the full buffer tile
+        nb = -(-elems // (bucket_elems * SUBLANES)) * SUBLANES
+        group_buckets.append(max(SUBLANES, nb))
     # flag_index is derived in __post_init__ (tail of the last group) —
     # one owner for the flag-position invariant
     return BucketLayout(treedef=treedef, shapes=shapes, dtypes=dtypes,

@@ -73,6 +73,10 @@ class ProgramCache:
             self._programs.popitem(last=False)
         return prog
 
+    def programs(self) -> list:
+        """The cached programs, least recently used first."""
+        return list(self._programs.values())
+
     def __contains__(self, pc: PhaserCollective) -> bool:
         return self.full_key(pc) in self._programs
 

@@ -12,7 +12,7 @@ into an executable ``shard_map`` train step over a real mesh axis:
      reduced alive count, and the optimizer update runs replicated.
 
 Params and optimizer state are replicated (``P()``); batch and alive
-mask are sharded over the data axis. ``check_rep=False`` because Pallas
+mask are sharded over the data axis. ``check_vma=False`` because Pallas
 calls carry no replication rule — the schedule itself guarantees every
 rank ends with the same reduced buffer (tested against ``xla_psum``).
 
@@ -40,7 +40,6 @@ from typing import Any, Callable, Dict, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..core.collective import PhaserCollective
@@ -228,10 +227,10 @@ def build_gradsync_program(api, opt, pc: PhaserCollective, *,
               for k, v in pm.items()}
         return new_p, new_o, pm
 
-    sm = shard_map(worker, mesh=mesh,
-                   in_specs=(P(), P(), P(axis), P(axis)),
-                   out_specs=(P(), P(), P(axis)),
-                   check_rep=False)
+    sm = jax.shard_map(worker, mesh=mesh,
+                       in_specs=(P(), P(), P(axis), P(axis)),
+                       out_specs=(P(), P(), P(axis)),
+                       check_vma=False)
     jitted = jax.jit(sm, donate_argnums=(0, 1) if donate else ())
     st = pc.stats()
     meta = {"team": pc.n, "sync_rounds": st["rounds"],
@@ -340,10 +339,10 @@ def build_hier_gradsync_program(api, opt, pc_proc: PhaserCollective, *,
               for k, v in pm.items()}
         return flat[None], pm
 
-    sm = jax.jit(shard_map(grads_worker, mesh=mesh,
-                           in_specs=(P(), P(), P(axis), P(axis)),
-                           out_specs=(P(axis), P(axis)),
-                           check_rep=False))
+    sm = jax.jit(jax.shard_map(grads_worker, mesh=mesh,
+                               in_specs=(P(), P(), P(axis), P(axis)),
+                               out_specs=(P(axis), P(axis)),
+                               check_vma=False))
 
     def local_grads(params, opt_state, batch, alive):
         stacked_flat, pm = sm(params, opt_state, batch, alive)
@@ -389,5 +388,7 @@ def build_allreduce_program(pc: PhaserCollective, spec, *,
         tree, _ = layout.unflatten(flat)
         return tree["x"][None].astype(x.dtype)
 
-    return jax.jit(shard_map(worker, mesh=mesh, in_specs=P(pc.axis_name),
-                             out_specs=P(pc.axis_name), check_rep=False))
+    return jax.jit(jax.shard_map(worker, mesh=mesh,
+                                 in_specs=P(pc.axis_name),
+                                 out_specs=P(pc.axis_name),
+                                 check_vma=False))

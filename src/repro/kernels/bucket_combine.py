@@ -11,10 +11,13 @@ in SMEM, so the same compiled kernel serves every round of the schedule:
 * ``op="add"``  — reduce rounds: ``acc + gate * incoming``
 * ``op="copy"`` — broadcast/hydration rounds: ``gate ? incoming : acc``
 
-Each bucket row is one VMEM block (buckets are sized by the engine to a
-few hundred KB, well under the ~16 MB VMEM budget for the three
-operands); off-TPU callers run the same kernel body under the
-interpreter.
+A VMEM block is 8 bucket rows (one f32 sublane tile; the TPU compiler
+takes a block whose last two dims divide by (8, 128) or equal the
+operand's), or all rows of an operand with fewer than 8. The engine pads
+every larger group to a multiple of 8 buckets (``buckets.make_layout``)
+and caps a bucket row at ``MAX_BUCKET_BYTES``, so the three operands,
+double-buffered, stay inside ``VMEM_BUDGET``. Off-TPU callers run the
+same kernel body under the interpreter.
 
 **Variable-group launch**: the grid is derived from the operand's row
 count, so the same kernel serves the eager executor (one launch over
@@ -32,8 +35,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# 3 operands (acc, incoming, out) must fit VMEM together; stay well clear.
-MAX_BUCKET_BYTES = 4 * 1024 * 1024
+SUBLANES = 8                         # rows per block (f32 tile height)
+# under v5e's 16 MiB default scoped VMEM: 3 operands (acc, incoming,
+# out), each double-buffered, of one block of SUBLANES bucket rows
+VMEM_BUDGET = 12 * 1024 * 1024
+MAX_BUCKET_BYTES = VMEM_BUDGET // (3 * 2 * SUBLANES)   # 256 KiB
 
 
 def _combine_kernel(gate_ref, acc_ref, y_ref, o_ref, *, op: str):
@@ -62,18 +68,21 @@ def bucket_combine(acc: jax.Array, y: jax.Array, gate: jax.Array, *,
         return acc
     assert be * acc.dtype.itemsize <= MAX_BUCKET_BYTES, \
         f"bucket row of {be} elems exceeds the VMEM block budget"
+    rows = min(nb, SUBLANES)
+    assert nb % rows == 0, \
+        f"{nb} bucket rows: pad to a multiple of {SUBLANES}"
     kernel = functools.partial(_combine_kernel, op=op)
     gate2 = jnp.asarray(gate).astype(jnp.int32).reshape(1, 1)
     return pl.pallas_call(
         kernel,
-        grid=(nb,),
+        grid=(nb // rows,),
         in_specs=[
             pl.BlockSpec((1, 1), lambda i: (0, 0),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, be), lambda i: (i, 0)),
-            pl.BlockSpec((1, be), lambda i: (i, 0)),
+            pl.BlockSpec((rows, be), lambda i: (i, 0)),
+            pl.BlockSpec((rows, be), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, be), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((rows, be), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(acc.shape, acc.dtype),
         interpret=interpret,
     )(gate2, acc, y)

@@ -3,6 +3,16 @@ importing this module never touches jax device state."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """A device mesh whose axes are all ``Auto``: the rules path places
+    arrays with explicit NamedShardings and the engine's programs run
+    under ``shard_map``, neither of which takes ``Explicit`` axes (the
+    default of ``jax.make_mesh``)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -12,11 +22,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     (DCN-class) collectives."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape, axes):
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return make_mesh(shape, axes)
 
 
 # TPU v5e-class hardware constants used by the roofline analysis.

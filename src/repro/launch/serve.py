@@ -13,6 +13,7 @@ import numpy as np
 
 from ..models.registry import get_api, get_config
 from ..serve.engine import Request, ServeEngine
+from ..utils import enable_compile_cache
 
 
 def main(argv=None):
@@ -25,6 +26,7 @@ def main(argv=None):
     ap.add_argument("--prompt-len", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=12)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -65,6 +65,7 @@ from ..models.registry import get_api, get_config
 from ..optim import AdamW
 from ..runtime_elastic import ElasticPhaserRuntime
 from ..train.loop import TrainLoop
+from ..utils import enable_compile_cache
 
 
 def parse_elastic(spec: str):
@@ -184,6 +185,9 @@ def run_processes(args, ap):
                          proc_kind=args.sync_kind, data_for=data_for,
                          obs=obs, live_out=args.live_out,
                          flight_dir=args.flight_dir)
+    if args.fabric in ("socket", "tcp"):
+        for pid, plat in sorted(rt.cluster.platforms.items()):
+            print(f"# host process {pid}: jax platform {plat}")
     start = 0
     if args.resume and args.ckpt_dir:
         mk = rt.cluster.call(min(rt.live),
@@ -280,6 +284,13 @@ def run_processes(args, ap):
 
 
 def main(argv=None):
+    return run(argv)[0]
+
+
+def run(argv=None):
+    """Parse ``argv`` and train; returns ``(exit code, TrainLoop)`` (the
+    loop is None with ``--processes``), so callers can read the metrics
+    and the epoch programs of the run."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m")
     ap.add_argument("--steps", type=int, default=100)
@@ -294,6 +305,9 @@ def main(argv=None):
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--microbatches", type=int, default=1)
+    ap.add_argument("--log-every", type=int, default=10,
+                    help="record (and print) the metrics of every Nth "
+                         "step, and of the last")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--workers", type=int, default=4,
                     help="initial elastic worker-group size")
@@ -394,12 +408,14 @@ def main(argv=None):
             + f" --xla_force_host_platform_device_count={args.host_devices}"
         ).strip()
         if len(jax.devices()) != args.host_devices:
-            print(f"# --host-devices {args.host_devices}: backend already "
-                  f"initialized with {len(jax.devices())} devices; set "
-                  "XLA_FLAGS before launch instead")
+            ap.error(f"--host-devices {args.host_devices}: the jax backend "
+                     f"already has {len(jax.devices())} "
+                     f"{jax.devices()[0].platform} devices; set XLA_FLAGS "
+                     "before launch instead")
 
     if args.processes > 1:
-        return run_processes(args, ap)
+        return run_processes(args, ap), None
+    enable_compile_cache()
     if args.elastic is not None and "kill" in args.elastic:
         try:
             ev = parse_elastic(args.elastic)
@@ -444,6 +460,7 @@ def main(argv=None):
     loop = TrainLoop(api=api, opt=opt, data=data, ckpt=ckpt,
                      ckpt_every=args.ckpt_every,
                      microbatches=args.microbatches,
+                     log_every=args.log_every,
                      timeline=timeline, metrics=metrics_reg,
                      runtime=runtime,
                      elastic_events=events or {},
@@ -459,7 +476,7 @@ def main(argv=None):
         loop.run(args.steps, resume=args.resume)
     except ValueError as e:
         print(f"# elastic schedule error: {e}")
-        return 2
+        return 2, loop
     if args.trace:
         timeline.save(args.trace)
     if args.metrics_out:
@@ -475,7 +492,7 @@ def main(argv=None):
     last = loop.metrics_log[-1]["loss"]
     print(f"# loss {first:.4f} -> {last:.4f} "
           f"({'DECREASED' if last < first else 'NOT DECREASED'})")
-    return 0 if last < first else 1
+    return (0 if last < first else 1), loop
 
 
 if __name__ == "__main__":

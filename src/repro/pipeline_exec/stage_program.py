@@ -64,7 +64,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..collective_exec.buckets import make_layout
@@ -409,10 +408,10 @@ def build_pipeline_program(api, opt, pc: PhaserCollective, *,
               for k, val in pm.items()}
         return new_p, new_o, pm
 
-    sm = shard_map(worker, mesh=mesh,
-                   in_specs=(param_ps, opt_ps, P(axis), P(axis)),
-                   out_specs=(param_ps, opt_ps, P(axis)),
-                   check_rep=False)
+    sm = jax.shard_map(worker, mesh=mesh,
+                       in_specs=(param_ps, opt_ps, P(axis), P(axis)),
+                       out_specs=(param_ps, opt_ps, P(axis)),
+                       check_vma=False)
 
     # the step is compiled over the device-major layout directly —
     # carried state stays put between steps, so the interleaved program

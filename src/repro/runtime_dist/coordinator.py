@@ -201,6 +201,7 @@ class SocketCluster:
         self.ep = (FaultyEndpoint(ep, chaos, metrics=self.metrics)
                    if chaos is not None else ep)
         self.procs: Dict[int, subprocess.Popen] = {}
+        self.platforms: Dict[int, Optional[str]] = {}  # pid -> jax platform
         self.env_sink: Optional[Callable] = None
         self.control_only = control_only
         self.python = python or sys.executable
@@ -264,6 +265,8 @@ class SocketCluster:
         env["PHASER_ORPHAN_TIMEOUT"] = str(self.orphan_timeout)
         data = cfg.get("data")
         if data is not None:
+            # a chip belongs to one process: host processes share the
+            # host CPU as a simulated mesh, and say so in their init reply
             env["JAX_PLATFORMS"] = "cpu"
             env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
                                 f"{data.get('devices', 1)}")
@@ -278,6 +281,7 @@ class SocketCluster:
         self._spawn(pid, cfg)
         r = self.call(pid, {"op": "init", "cfg": cfg}, timeout=600.0)
         assert r.get("ok"), (pid, r)
+        self.platforms[pid] = r.get("platform")
 
     def kill_pid(self, pid: int) -> None:
         """Hard crash for tests/chaos: SIGKILL, no cleanup whatsoever —

@@ -69,6 +69,15 @@ def _flush_flight(agent, directory: str, pid: int, reason: str) -> None:
         pass                    # best effort: never mask the exit path
 
 
+def _platform(cfg: dict):
+    """The jax platform this host's data plane runs on, reported in the
+    ``init`` reply; None for a control-plane-only host (no jax)."""
+    if cfg.get("data") is None:
+        return None
+    import jax
+    return jax.devices()[0].platform
+
+
 def _send_rep(ep, src: int, cid: int, reply: dict) -> None:
     """A lost reply must not kill the worker: the RPC layer is
     at-least-once, so the coordinator retransmits the command, the cid
@@ -135,7 +144,8 @@ def serve(pid: int, directory: str,
                         agent.hold_red(f)
                     pending_red.clear()
                     agent.shard.net.deliver_all()
-                    reply = {"ok": True, "pid": pid}
+                    reply = {"ok": True, "pid": pid,
+                             "platform": _platform(cmd["cfg"])}
                 elif cmd["op"] == "shutdown":
                     ep.send(src, "rep", (cid, {"ok": True}))
                     return 0

@@ -83,6 +83,14 @@ class TrainLoop:
     _progs: Any = field(default=None, init=False, repr=False)
 
     @property
+    def programs(self) -> List:
+        """The epoch programs of the device-collective path, least
+        recently used first (empty on the plain jit path)."""
+        if self._progs is None:
+            return []
+        return [ts.program for ts in self._progs.programs()]
+
+    @property
     def _overlap_mode(self) -> str:
         return "pipelined" if self.overlap_sync else "eager"
 

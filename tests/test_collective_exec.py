@@ -127,7 +127,10 @@ def test_bucket_layout_roundtrip():
 def test_bucket_layout_multi_bucket_sizing():
     spec = {"x": jax.ShapeDtypeStruct((1000,), jnp.float32)}
     lay = make_layout(spec, bucket_elems=256)
-    assert lay.n_buckets == math.ceil(1001 / 256)
+    # ceil(1001 / 256) = 4 buckets hold the payload and the flag; the
+    # count rounds up to whole 8-row blocks of the combine kernel
+    assert lay.n_buckets == 8 * math.ceil(math.ceil(1001 / 256) / 8)
+    assert lay.flag_index == 1000
     buf = lay.flatten({"x": jnp.ones((1000,), jnp.float32)}, 0.0)
     out, count = lay.unflatten(buf)
     assert float(count) == 0.0

@@ -126,6 +126,26 @@ def test_bucket_combine_vs_ref(op, gate):
     np.testing.assert_allclose(np.asarray(out), want, rtol=1e-6)
 
 
+@pytest.mark.parametrize("op", ["add", "copy"])
+@pytest.mark.parametrize("rows", [8, 24])
+def test_bucket_combine_row_blocks_bitwise(rows, op):
+    """Whole 8-row blocks (one grid step or several) combine exactly as
+    the elementwise reference, bit for bit."""
+    from repro.kernels.ops import bucket_combine_op
+
+    rng = np.random.default_rng(rows)
+    acc = rng.normal(size=(rows, 384)).astype(np.float32)
+    y = rng.normal(size=(rows, 384)).astype(np.float32)
+    for gate in (False, True):
+        out = bucket_combine_op(jnp.asarray(acc), jnp.asarray(y),
+                                jnp.asarray(gate), op=op, interpret=True)
+        if op == "add":
+            want = acc + y if gate else acc
+        else:
+            want = y if gate else acc
+        np.testing.assert_array_equal(np.asarray(out), want)
+
+
 def test_bucket_combine_executes_schedule_like_simulate():
     """Chained combines reproduce the host simulate_schedule semantics
     on a 3-rank elimination schedule (kernel as the round primitive)."""
