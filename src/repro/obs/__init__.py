@@ -34,7 +34,7 @@ from .metrics import Counter, Gauge, Histogram, MetricsRegistry, \
     default_registry
 from .recorder import FlightRecorder, check_flight_file, flight_path
 from .timeline import Timeline, activate, current, deactivate, \
-    gradsync_round_events, pipeline_wave_events
+    gradsync_round_events, pipeline_wave_events, span
 from .trace import SpanCtx, SpanId, Tracer, TraceStore, check_signal_hops
 
 __all__ = [
@@ -44,5 +44,5 @@ __all__ = [
     "WatermarkRegression", "WatermarkTracker", "activate",
     "check_flight_file", "check_signal_hops", "current", "deactivate",
     "default_registry", "flight_path", "gradsync_round_events",
-    "pipeline_wave_events", "read_frames", "spans_path",
+    "pipeline_wave_events", "read_frames", "span", "spans_path",
 ]

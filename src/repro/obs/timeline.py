@@ -17,10 +17,17 @@ The module-level ``activate``/``current`` hook is how trace-time code
 deep inside the executors reaches the live timeline without threading
 it through every builder signature; when no timeline is active the
 hooks cost one ``None`` check.
+
+``span(name, registry)`` is the program's always-on host span: it times
+a block into the registry's ``<name>.seconds`` histogram, records it on
+the active timeline, and, once jax is imported, enters a
+``jax.profiler.TraceAnnotation`` so a running profiler places the span
+on the same clock as the device ops.
 """
 from __future__ import annotations
 
 import json
+import sys
 import time
 from contextlib import contextmanager
 from typing import Dict, List, Optional
@@ -110,6 +117,33 @@ def deactivate() -> None:
 
 def current() -> Optional[Timeline]:
     return _ACTIVE
+
+
+@contextmanager
+def span(name: str, registry, **args):
+    """Time a block of program work: ``<name>.seconds`` in ``registry``
+    (a ``MetricsRegistry``), an X event on the active timeline, and a
+    ``TraceAnnotation`` carrying ``args`` for a running profiler. jax is
+    only used when some other module has already imported it, so
+    jax-free processes stay jax-free. Always on: with no profiler and no
+    timeline the cost is one pybind enter/exit and one histogram
+    update."""
+    jax = sys.modules.get("jax")
+    ann = (jax.profiler.TraceAnnotation(name, **args)
+           if jax is not None else None)
+    tl = _ACTIVE
+    start_us = tl.now() if tl is not None else 0.0
+    t0 = time.perf_counter()
+    if ann is not None:
+        ann.__enter__()
+    try:
+        yield
+    finally:
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        registry.observe(name + ".seconds", time.perf_counter() - t0)
+        if tl is not None:
+            tl.complete(name, start_us, args=args or None)
 
 
 # ---------------------------------------------------------------------------

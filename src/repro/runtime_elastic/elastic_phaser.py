@@ -38,6 +38,8 @@ from ..core.collective import (ALLREDUCE_KINDS, PhaserCollective,
 from ..core.phaser import SCSL, SNSL, SIG_WAIT, DistPhaser
 from ..core.runtime import FifoScheduler, Scheduler
 from ..core.skiplist import HEAD, SkipList
+from ..obs.metrics import MetricsRegistry
+from ..obs.timeline import span
 from .strikes import StrikeAction, StrikeEscalation
 
 
@@ -77,13 +79,22 @@ class ElasticPhaserRuntime:
     for any team size (non-power-of-two teams get the elimination
     derivations in ``core/collective.py``), so an epoch's kind equals the
     preference — the historical fallback to ``phaser_scsl`` is gone.
+
+    ``metrics`` is the registry the protocol's host cost lands in (a
+    private one when not given): spans ``phaser.advance``,
+    ``phaser.join`` and ``phaser.leave``, the counter
+    ``phaser.deliveries`` (every message the schedulers delivered), and
+    at each advance the gauges ``phaser.channels`` (channels the network
+    ever opened), ``phaser.actors`` and ``phaser.epochs``.
     """
 
     def __init__(self, n_workers: int, *, seed: int = 0,
                  kind: str = "phaser_scsl",
                  scheduler: Optional[Callable[[], Scheduler]] = None,
-                 axis_name: str = "data"):
+                 axis_name: str = "data",
+                 metrics: Optional[MetricsRegistry] = None):
         assert kind in ALLREDUCE_KINDS, kind
+        self.metrics = metrics if metrics is not None else MetricsRegistry()
         self.seed = seed
         self.kind = kind
         self.axis_name = axis_name
@@ -159,6 +170,11 @@ class ElasticPhaserRuntime:
                                                      & self.live)))
         return Epoch(index, phase_start, keys, k, pc)
 
+    def _run(self) -> None:
+        """Drive the protocol to quiescence, counting the deliveries."""
+        self.metrics.inc("phaser.deliveries",
+                         self.ph.run(self._make_scheduler()))
+
     # ------------------------------------------------------------- churn
     def request_join(self, parent: Optional[int] = None,
                      *, step: Optional[int] = None,
@@ -170,8 +186,9 @@ class ElasticPhaserRuntime:
         self.next_worker_id += 1
         if parent is None:
             parent = min(self.live) if self.live else HEAD
-        self.ph.async_add(parent, wid, mode)
-        self.ph.run(self._make_scheduler())     # splice + lazy promotion
+        with span("phaser.join", self.metrics):
+            self.ph.async_add(parent, wid, mode)
+            self._run()                         # splice + lazy promotion
         self.live.add(wid)
         self.events.append(WorkerEvent(self._at(step), "join", wid))
         self._dirty = True
@@ -183,8 +200,9 @@ class ElasticPhaserRuntime:
         expectation so the in-flight phase completes without the worker;
         level-by-level unlink runs to quiescence."""
         assert worker in self.live, (worker, sorted(self.live))
-        self.ph.drop(worker)
-        self.ph.run(self._make_scheduler())
+        with span("phaser.leave", self.metrics):
+            self.ph.drop(worker)
+            self._run()
         self.live.discard(worker)
         self._strikes.pop(worker, None)
         self.events.append(WorkerEvent(self._at(step),
@@ -201,7 +219,7 @@ class ElasticPhaserRuntime:
         if worker in self.ph.demoted:
             return
         self.ph.demote(worker)
-        self.ph.run(self._make_scheduler())
+        self._run()
         self.events.append(WorkerEvent(self._at(step), "demote", worker))
         self._dirty = True
 
@@ -211,7 +229,7 @@ class ElasticPhaserRuntime:
         if worker not in self.live or worker not in self.ph.demoted:
             return
         self.ph.repromote(worker)
-        self.ph.run(self._make_scheduler())
+        self._run()
         self.events.append(WorkerEvent(self._at(step), "repromote", worker))
         self._dirty = True
 
@@ -228,21 +246,25 @@ class ElasticPhaserRuntime:
         quiescence, and — if membership changed during the closing epoch —
         the boundary derives the next epoch's schedule. Returns the head's
         released phase."""
-        for w in sorted(self.live):
-            a = self.ph.actors[w]
-            if a.sc.member and not a.sc.dropping:
-                self.ph.signal(w)
-        self.ph.run(self._make_scheduler())
-        released = self.ph.released()
-        if self._dirty:
-            old = self.epoch
-            new = self._derive_epoch(old.index + 1, released + 1)
-            self.epochs.append(new)
-            self._dirty = False
-            if self._on_epoch:
-                self._compile_pending = True   # boundary hooks re-lower
-            for fn in self._on_epoch:
-                fn(old, new)
+        with span("phaser.advance", self.metrics):
+            for w in sorted(self.live):
+                a = self.ph.actors[w]
+                if a.sc.member and not a.sc.dropping:
+                    self.ph.signal(w)
+            self._run()
+            released = self.ph.released()
+            if self._dirty:
+                old = self.epoch
+                new = self._derive_epoch(old.index + 1, released + 1)
+                self.epochs.append(new)
+                self._dirty = False
+                if self._on_epoch:
+                    self._compile_pending = True   # boundary hooks re-lower
+                for fn in self._on_epoch:
+                    fn(old, new)
+        self.metrics.set("phaser.channels", len(self.ph.net.channels))
+        self.metrics.set("phaser.actors", len(self.ph.actors))
+        self.metrics.set("phaser.epochs", len(self.epochs))
         if step is not None:
             self._step = step
         self._step += 1

@@ -60,6 +60,7 @@ import numpy as np
 from ..configs.base import ModelConfig
 from ..models.registry import ModelAPI
 from ..obs.metrics import MetricsRegistry
+from ..obs.timeline import span
 from ..runtime_elastic.elastic_phaser import ElasticPhaserRuntime
 from ..utils import to_device_copy
 
@@ -86,19 +87,22 @@ class ServeEngine:
         self.slot_req: List[Optional[Request]] = [None] * batch
         self.slot_pos = np.zeros((batch,), np.int32)
         self.queue: List[Request] = []
+        # per-engine metrics shard (obs plane), shared with the gate so
+        # one shard holds the whole serve path: trace counters,
+        # admission kinds, queue wait, and the spans ``serve.admit``,
+        # ``serve.decode`` and the gate's ``phaser.*``. The legacy
+        # ``prefill_traces``/``prefill_state_traces`` attributes are
+        # read-only views over these counters.
+        self.metrics = MetricsRegistry()
         # control plane: occupied slots are phaser participants; admission
         # keys are monotone (a slot reused by a later request is a new
         # participant — phaser keys are never recycled)
-        self.gate = ElasticPhaserRuntime(0, seed=seed, axis_name="slots")
+        self.gate = ElasticPhaserRuntime(0, seed=seed, axis_name="slots",
+                                         metrics=self.metrics)
         self.slot_key: List[Optional[int]] = [None] * batch
         self.finished: List[Request] = []
         # no donation: _admit snapshots the pre-prefill state for splicing
         self._decode = jax.jit(api.decode_fn)
-        # per-engine metrics shard (obs plane): trace counters,
-        # admission kinds, retire counts, decode occupancy. The legacy
-        # ``prefill_traces``/``prefill_state_traces`` attributes are
-        # read-only views over these counters.
-        self.metrics = MetricsRegistry()
         # full-logits prefill: length-bucketed groups read each
         # request's next token at its true len-1, not the padded tail.
         # The trace counters tick ONCE per lowering (the wrapped python
@@ -199,11 +203,18 @@ class ServeEngine:
         groups (same power-of-two length bucket) run one padded prefill
         forward (KV families) or one length-masked decode scan
         (recurrent families) each and splice their states in; everything
-        else falls back to token-by-token prefill."""
+        else falls back to token-by-token prefill. The ``serve.admit``
+        span covers the prefills, splices and first-token read-backs."""
         admits: List[Tuple[int, Request]] = []
         for slot in range(self.batch):
             if self.slot_req[slot] is None and self.queue:
                 admits.append((slot, self.queue.pop(0)))
+        if admits:
+            with span("serve.admit", self.metrics,
+                      rids=[r.rid for _, r in admits]):
+                self._admit_groups(admits)
+
+    def _admit_groups(self, admits: List[Tuple[int, Request]]) -> None:
         groups: Dict[Tuple[str, int], List[Tuple[int, Request]]] = {}
         for slot, req in admits:
             # clamp to the window so a non-pow2 window keeps its largest
@@ -221,7 +232,6 @@ class ServeEngine:
                 self._admit_sequential(slot, req)
         for (kind, bucket), group in sorted(groups.items()):
             self.metrics.inc(f"serve.admit.{kind}", len(group))
-            self.metrics.observe("serve.admit.group_size", len(group))
             if kind == "kv":
                 self._admit_bulk(group, bucket)
             else:
@@ -366,7 +376,6 @@ class ServeEngine:
         """LEAVE: the finished request's participant deregisters; the
         slot is reclaimed for the next boundary's refill."""
         self.finished.append(self.slot_req[slot])
-        self.metrics.inc("serve.retired")
         self.gate.request_leave(self.slot_key[slot])
         self.slot_key[slot] = None
         self.slot_req[slot] = None
@@ -383,8 +392,6 @@ class ServeEngine:
         boundary, retires at the trailing one) land as gate epochs."""
         self._admit()
         active = [i for i, r in enumerate(self.slot_req) if r is not None]
-        self.metrics.set("serve.occupancy", len(active))
-        self.metrics.observe("serve.active_slots", len(active))
         if not active:
             if self.gate.pending_churn:
                 # a request was admitted AND retired inside _admit (e.g.
@@ -396,14 +403,11 @@ class ServeEngine:
         for i in active:
             r = self.slot_req[i]
             token_b[i] = r.out[-1] if r.out else r.prompt[-1]
-        self.metrics.inc("serve.decode.steps")
-        t0 = time.perf_counter()
-        logits, self.state = self._dispatch(token_b, self.slot_pos)
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
-        # np.asarray forced the device sync: this is the real per-token
-        # decode latency of the whole batch (p50/p99 from the buckets)
-        self.metrics.observe("serve.decode.token_seconds",
-                             time.perf_counter() - t0)
+        # np.asarray forces the device sync: the span is the real
+        # per-token decode latency of the whole batch, tokens on the host
+        with span("serve.decode", self.metrics):
+            logits, self.state = self._dispatch(token_b, self.slot_pos)
+            nxt = np.asarray(jnp.argmax(logits, axis=-1))
         for i in active:
             r = self.slot_req[i]
             r.out.append(int(nxt[i]))

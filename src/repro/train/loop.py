@@ -77,7 +77,7 @@ class TrainLoop:
     interleave: int = 1
     # obs plane (optional): an active ``timeline`` receives wall-clock
     # step/relower spans plus the logical schedule grids the executors
-    # emit at trace time; ``metrics`` shards step timings and cache hits
+    # emit at trace time; ``metrics`` shards relower counts and cache hits
     timeline: Optional[obs_timeline.Timeline] = None
     metrics: Optional[MetricsRegistry] = None
     _progs: Any = field(default=None, init=False, repr=False)
@@ -300,9 +300,6 @@ class TrainLoop:
             if self.timeline is not None:
                 self.timeline.complete("train.step", tp0,
                                        args={"step": step})
-            if self.metrics is not None:
-                self.metrics.observe("train.step_seconds",
-                                     time.time() - t0)
             if self.runtime is not None:
                 # the step is one phaser phase; churn requested above
                 # lands as a new epoch exactly at this boundary

@@ -512,25 +512,39 @@ def test_regress_committed_baseline_passes_committed_artifacts():
 
 
 # --------------------------------------------------- serve latency hists
-def test_serve_engine_latency_histograms():
-    """Admission queue-wait and per-token decode latency land in the
-    engine's metrics shard as histograms with readable quantiles."""
+def _serve_engine(batch: int = 2, window: int = 32):
+    """The reduced smollm-135m engine the serve tests drive."""
     jax = pytest.importorskip("jax")
-    import numpy as np
     from repro.models.registry import get_api, get_config
-    from repro.serve.engine import Request, ServeEngine
+    from repro.serve.engine import ServeEngine
 
     cfg = get_config("smollm-135m").reduced()
     api = get_api(cfg)
     params = api.init_params(jax.random.key(0))
-    eng = ServeEngine(api, params, batch=2, window=32)
+    return ServeEngine(api, params, batch=batch, window=window)
+
+
+def _requests(n: int):
+    import numpy as np
+    from repro.serve.engine import Request
+    return [Request(rid=i, prompt=np.array([1 + i, 2, 3, 4][:2 + i % 3],
+                                           np.int32), max_new=2 + i % 3)
+            for i in range(n)]
+
+
+def test_serve_engine_latency_histograms():
+    """Admission queue-wait and per-token decode latency land in the
+    engine's metrics shard as histograms with readable quantiles."""
+    eng = _serve_engine()
+    import numpy as np
+    from repro.serve.engine import Request
     for i in range(3):
         eng.submit(Request(rid=i, prompt=np.array([1 + i, 2, 3],
                                                   np.int32), max_new=2))
     eng.run_until_drained()
     snap = eng.metrics.snapshot()["hists"]
     qw = snap["serve.admit.queue_wait_seconds"]
-    tok = snap["serve.decode.token_seconds"]
+    tok = snap["serve.decode.seconds"]
     assert qw["count"] == 3                    # one wait per admission
     assert tok["count"] >= 2                   # one observation per step
     for h in (qw, tok):
@@ -539,8 +553,88 @@ def test_serve_engine_latency_histograms():
         assert p50 is not None and p99 is not None and p99 >= p50 > 0
     # bucket counts carry the mass (quantiles work on merged shards)
     merged = MetricsRegistry.merge([eng.metrics.snapshot()])
-    assert sum(merged["hists"]["serve.decode.token_seconds"]
+    assert sum(merged["hists"]["serve.decode.seconds"]
                ["buckets"]) == tok["count"]
+
+
+def test_serve_spans_count_admissions_decodes_and_gate():
+    """One ``serve.admit`` per step that admits, one ``serve.decode`` per
+    step that decodes, one ``phaser.advance`` per gate advance, one
+    ``phaser.join`` and one ``phaser.leave`` per request; the engine's
+    shard holds the gate's counters, and the spans of a drained run fit
+    in its wall time."""
+    eng = _serve_engine()
+    reqs = _requests(5)
+    for r in reqs:
+        eng.submit(r)
+    advances = [0]
+    advance = eng.gate.advance
+
+    def counted(**kw):
+        advances[0] += 1
+        return advance(**kw)
+
+    eng.gate.advance = counted
+    admitting = decoding = 0
+    t0 = time.perf_counter()
+    while True:
+        admitting += bool(eng.queue) and None in eng.slot_req
+        n = eng.step()
+        decoding += n > 0
+        if n == 0 and not eng.queue:
+            break
+    wall = time.perf_counter() - t0
+    assert all(r.done for r in reqs)
+    reg = eng.metrics
+    hist = lambda name: reg.histogram(name + ".seconds")
+    assert hist("serve.admit").count == admitting >= 3
+    assert hist("serve.decode").count == decoding
+    assert hist("phaser.advance").count == advances[0] >= decoding
+    assert hist("phaser.join").count == len(reqs)
+    assert hist("phaser.leave").count == len(reqs)
+    # joins nest inside admissions; every leave here follows a decode,
+    # outside the other spans, so the top-level spans are disjoint
+    assert hist("phaser.join").total <= hist("serve.admit").total
+    top = sum(hist(n).total for n in ("serve.admit", "serve.decode",
+                                      "phaser.advance", "phaser.leave"))
+    assert 0 < top <= wall
+    assert reg.counter("phaser.deliveries").value > 0
+    ph = eng.gate.ph
+    assert reg.gauge("phaser.channels").value == len(ph.net.channels)
+    assert reg.gauge("phaser.actors").value == len(ph.actors)
+    assert reg.gauge("phaser.epochs").value == len(eng.gate.epochs)
+
+
+def test_serve_engine_unchanged_under_profiler(tmp_path):
+    """With ``jax.profiler`` tracing around it the engine answers as it
+    does with no profiler, and the trace's host plane holds the
+    program's spans, the admissions with their request ids."""
+    jax = pytest.importorskip("jax")
+    import glob
+    plain = _serve_engine()
+    for r in _requests(4):
+        plain.submit(r)
+    want = [(r.rid, r.out) for r in plain.run_until_drained()]
+    traced = _serve_engine()
+    for r in _requests(4):
+        traced.submit(r)
+    with jax.profiler.trace(str(tmp_path)):
+        got = [(r.rid, r.out) for r in traced.run_until_drained()]
+    assert got == want
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    names = ("serve.admit", "serve.decode", "phaser.advance",
+             "phaser.join", "phaser.leave")
+    seen = {}
+    for plane in pd.planes:
+        if plane.name.startswith("/host:CPU"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name in names:
+                        seen.setdefault(ev.name, []).append(dict(ev.stats))
+    assert sorted(seen) == sorted(names)
+    rids = "".join(st.get("rids", "") for st in seen["serve.admit"])
+    assert all(str(i) in rids for i in range(4)), rids
 
 
 # ------------------------------------------- slow: real process boundaries

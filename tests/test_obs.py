@@ -13,9 +13,12 @@ from __future__ import annotations
 
 import json
 import os
+import subprocess
+import sys
 
 import pytest
 
+from repro.core.runtime import FifoScheduler
 from repro.obs import (MetricsRegistry, Timeline, TraceStore,
                        check_signal_hops, pipeline_wave_events)
 from repro.runtime_dist import COORD, DistCoordinator, InprocCluster
@@ -254,6 +257,91 @@ def test_timeline_chrome_export_and_wave_grid(tmp_path):
     tl.save_jsonl(str(tmp_path / "tl.jsonl"))
     assert len(open(str(tmp_path / "tl.jsonl")).readlines()) == \
         len(chrome["traceEvents"])
+
+
+# ------------------------------------------------------------ program spans
+def test_span_times_into_histogram_and_active_timeline():
+    """``span`` adds its elapsed seconds to ``<name>.seconds`` and, while
+    a timeline is active, records the same interval there with its
+    arguments; an exception inside still closes the span."""
+    from repro.obs import activate, deactivate, span
+    reg = MetricsRegistry()
+    with span("unit.work", reg):
+        pass
+    tl = Timeline(pid=3)
+    activate(tl)
+    try:
+        with span("unit.work", reg, rids=[7, 8]):
+            pass
+        with pytest.raises(KeyError):
+            with span("unit.fail", reg):
+                raise KeyError("x")
+    finally:
+        deactivate()
+    h = reg.histogram("unit.work.seconds")
+    assert h.count == 2 and h.total >= 0.0
+    assert reg.histogram("unit.fail.seconds").count == 1
+    got = [(e["name"], e["args"]) for e in tl.events]
+    assert got == [("unit.work", {"rids": [7, 8]}), ("unit.fail", {})]
+
+
+def test_span_keeps_jax_free_processes_jax_free():
+    """The control plane's processes never import jax: ``span`` only
+    annotates for the profiler when jax is already loaded."""
+    code = ("import sys\n"
+            "from repro.obs import MetricsRegistry, span\n"
+            "reg = MetricsRegistry()\n"
+            "with span('x', reg):\n"
+            "    pass\n"
+            "assert reg.histogram('x.seconds').count == 1\n"
+            "assert 'jax' not in sys.modules, 'span imported jax'\n")
+    env = {**os.environ, "PYTHONPATH": os.path.join(REPO, "src")}
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr
+
+
+class _CountingFifo(FifoScheduler):
+    """FifoScheduler that remembers what every ``run`` returned."""
+
+    returned = []
+
+    def run(self, net, max_steps=10_000_000):
+        n = super().run(net, max_steps)
+        _CountingFifo.returned.append(n)
+        return n
+
+
+def test_gate_counts_deliveries_and_sets_gauges():
+    """``phaser.deliveries`` is the sum of what the protocol's scheduler
+    runs returned; each join, leave and advance is one span; the gauges
+    set at an advance equal the sizes they name."""
+    from repro.runtime_elastic import ElasticPhaserRuntime
+    _CountingFifo.returned = []
+    reg = MetricsRegistry()
+    rt = ElasticPhaserRuntime(0, seed=1, axis_name="slots",
+                              scheduler=_CountingFifo, metrics=reg)
+    keys = []
+    for step in range(6):
+        keys.append(rt.request_join())
+        if step % 2:
+            rt.request_leave(keys.pop(0))
+        rt.advance()
+        assert reg.gauge("phaser.channels").value == len(rt.ph.net.channels)
+        assert reg.gauge("phaser.actors").value == len(rt.ph.actors)
+        assert reg.gauge("phaser.epochs").value == len(rt.epochs)
+    rt.request_demote(keys[-1])
+    rt.advance()
+    assert reg.counter("phaser.deliveries").value == \
+        sum(_CountingFifo.returned) > 0
+    assert reg.histogram("phaser.join.seconds").count == 6
+    assert reg.histogram("phaser.leave.seconds").count == 3
+    assert reg.histogram("phaser.advance.seconds").count == 7
+    # no registry given: the runtime keeps a private one
+    own = ElasticPhaserRuntime(2, seed=0)
+    own.advance()
+    assert own.metrics.histogram("phaser.advance.seconds").count == 1
+    assert own.metrics.counter("phaser.deliveries").value > 0
 
 
 # ------------------------------------------- real process boundaries (fast:
