@@ -16,6 +16,7 @@ message complexity.
 from __future__ import annotations
 
 import random
+from bisect import bisect_left, insort
 from collections import defaultdict, deque
 from dataclasses import dataclass, field
 from typing import Callable, Deque, Dict, List, Optional, Tuple
@@ -60,10 +61,22 @@ class Actor:
 class Network:
     """FIFO channels + stats. Delivery order across channels is the
     scheduler's choice; within a channel it is FIFO (matching the paper's
-    point-to-point ordering assumption)."""
+    point-to-point ordering assumption).
+
+    ``channels`` keeps every channel ever opened, empty ones included.
+    Beside it the network indexes the keys of its nonempty channels in
+    sorted order, so ``nonempty_channels()`` and ``idle()`` cost
+    O(nonempty channels): the cost of a delivery does not depend on how
+    many channels were ever opened. Every enqueue goes through
+    ``enqueue`` (``post`` and remote ingest) and every dequeue through
+    ``deliver_from``; ``drain_channels`` empties them all at once.
+    ``take_ready_peak`` reads the most nonempty channels the index has
+    held since it was last called."""
 
     def __init__(self):
         self.channels: Dict[Tuple[int, int], Deque[Envelope]] = defaultdict(deque)
+        self._ready: List[Tuple[int, int]] = []  # sorted nonempty keys
+        self._ready_peak = 0
         self.actors: Dict[int, Actor] = {}
         self.sent: Dict[str, int] = defaultdict(int)
         self.delivered: Dict[str, int] = defaultdict(int)
@@ -77,14 +90,41 @@ class Network:
 
     def post(self, env: Envelope) -> None:
         self.sent[env.msg.kind] += 1
-        self.channels[(env.msg.src, env.msg.dst)].append(env)
+        self.enqueue(env)
+
+    def enqueue(self, env: Envelope) -> None:
+        """Append ``env`` to its (src, dst) channel, indexing the
+        channel if it was empty."""
+        key = (env.msg.src, env.msg.dst)
+        q = self.channels[key]
+        if not q:
+            insort(self._ready, key)
+            if len(self._ready) > self._ready_peak:
+                self._ready_peak = len(self._ready)
+        q.append(env)
+
+    def drain_channels(self) -> List[Envelope]:
+        """Empty every channel; returns the envelopes that were in
+        flight, channel by channel in the order the channels opened."""
+        envs = [env for q in self.channels.values() for env in q]
+        self.channels.clear()
+        self._ready.clear()
+        return envs
+
+    def take_ready_peak(self) -> int:
+        """The most nonempty channels held since the previous call."""
+        peak, self._ready_peak = self._ready_peak, len(self._ready)
+        return peak
 
     # -- delivery -----------------------------------------------------------
     def nonempty_channels(self) -> List[Tuple[int, int]]:
-        return sorted(k for k, q in self.channels.items() if q)
+        return list(self._ready)
 
     def deliver_from(self, channel: Tuple[int, int]) -> Msg:
-        env = self.channels[channel].popleft()
+        q = self.channels[channel]
+        env = q.popleft()
+        if not q:
+            del self._ready[bisect_left(self._ready, channel)]
         actor = self.actors[env.msg.dst]
         actor.clock = max(actor.clock, env.depth)
         self.max_depth = max(self.max_depth, env.depth)
@@ -99,7 +139,7 @@ class Network:
         return env.msg
 
     def idle(self) -> bool:
-        return not any(self.channels.values())
+        return not self._ready
 
     # -- stats ----------------------------------------------------------------
     def total_sent(self) -> int:

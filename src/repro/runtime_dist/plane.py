@@ -115,7 +115,7 @@ class PartitionedNetwork(Network):
             self._blackhole(env)
             return
         self.remote_received += 1
-        self.channels[(env.msg.src, env.msg.dst)].append(env)
+        self.enqueue(env)
 
     def deliver_all(self, max_steps: int = 1_000_000) -> int:
         """Round-robin local delivery to local idleness (remote sends
@@ -387,10 +387,8 @@ class ShardPhaser:
                           live=sorted(self.live), gone=sorted(gone))
         # drop the old incarnation's in-flight frames, closing spans so
         # the causal trees stay complete
-        for q in self.net.channels.values():
-            for env in q:
-                self.net._blackhole(env)
-        self.net.channels.clear()
+        for env in self.net.drain_channels():
+            self.net._blackhole(env)
         self.net.gen = gen
         self.gen = gen
         # flight counters restart at zero on every survivor at the same

@@ -85,7 +85,11 @@ class ElasticPhaserRuntime:
     ``phaser.join`` and ``phaser.leave``, the counter
     ``phaser.deliveries`` (every message the schedulers delivered), and
     at each advance the gauges ``phaser.channels`` (channels the network
-    ever opened), ``phaser.actors`` and ``phaser.epochs``.
+    ever opened), ``phaser.ready_channels`` (the most channels that held
+    a message at once since the previous advance: what the schedulers
+    choose from), ``phaser.actors`` and ``phaser.epochs``. The network
+    indexes its nonempty channels, so the cost of a delivery does not
+    depend on how many channels the runtime's history has opened.
     """
 
     def __init__(self, n_workers: int, *, seed: int = 0,
@@ -263,6 +267,8 @@ class ElasticPhaserRuntime:
                 for fn in self._on_epoch:
                     fn(old, new)
         self.metrics.set("phaser.channels", len(self.ph.net.channels))
+        self.metrics.set("phaser.ready_channels",
+                         self.ph.net.take_ready_peak())
         self.metrics.set("phaser.actors", len(self.ph.actors))
         self.metrics.set("phaser.epochs", len(self.epochs))
         if step is not None:

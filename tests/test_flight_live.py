@@ -562,17 +562,22 @@ def test_serve_spans_count_admissions_decodes_and_gate():
     step that decodes, one ``phaser.advance`` per gate advance, one
     ``phaser.join`` and one ``phaser.leave`` per request; the engine's
     shard holds the gate's counters, and the spans of a drained run fit
-    in its wall time."""
+    in its wall time. Each advance sets ``phaser.ready_channels``, at
+    most ``phaser.channels``."""
     eng = _serve_engine()
     reqs = _requests(5)
     for r in reqs:
         eng.submit(r)
     advances = [0]
+    ready = []                   # (ready_channels, channels) per advance
     advance = eng.gate.advance
 
     def counted(**kw):
         advances[0] += 1
-        return advance(**kw)
+        out = advance(**kw)
+        ready.append((eng.metrics.gauge("phaser.ready_channels").value,
+                      eng.metrics.gauge("phaser.channels").value))
+        return out
 
     eng.gate.advance = counted
     admitting = decoding = 0
@@ -603,6 +608,9 @@ def test_serve_spans_count_admissions_decodes_and_gate():
     assert reg.gauge("phaser.channels").value == len(ph.net.channels)
     assert reg.gauge("phaser.actors").value == len(ph.actors)
     assert reg.gauge("phaser.epochs").value == len(eng.gate.epochs)
+    assert len(ready) == advances[0]
+    assert all(0 <= r <= c for r, c in ready)
+    assert max(r for r, _ in ready) > 0
 
 
 def test_serve_engine_unchanged_under_profiler(tmp_path):

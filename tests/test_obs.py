@@ -302,22 +302,32 @@ def test_span_keeps_jax_free_processes_jax_free():
 
 
 class _CountingFifo(FifoScheduler):
-    """FifoScheduler that remembers what every ``run`` returned."""
+    """FifoScheduler that remembers what every ``run`` returned, and the
+    most nonempty channels any of its steps chose from."""
 
     returned = []
+    widest = 0
 
     def run(self, net, max_steps=10_000_000):
         n = super().run(net, max_steps)
         _CountingFifo.returned.append(n)
         return n
 
+    def step(self, net):
+        _CountingFifo.widest = max(_CountingFifo.widest,
+                                   len(net.nonempty_channels()))
+        return super().step(net)
+
 
 def test_gate_counts_deliveries_and_sets_gauges():
     """``phaser.deliveries`` is the sum of what the protocol's scheduler
     runs returned; each join, leave and advance is one span; the gauges
-    set at an advance equal the sizes they name."""
+    set at an advance equal the sizes they name; ``phaser.ready_channels``
+    is the most nonempty channels the scheduler chose from since the
+    previous advance, at most ``phaser.channels``."""
     from repro.runtime_elastic import ElasticPhaserRuntime
     _CountingFifo.returned = []
+    _CountingFifo.widest = 0
     reg = MetricsRegistry()
     rt = ElasticPhaserRuntime(0, seed=1, axis_name="slots",
                               scheduler=_CountingFifo, metrics=reg)
@@ -330,6 +340,10 @@ def test_gate_counts_deliveries_and_sets_gauges():
         assert reg.gauge("phaser.channels").value == len(rt.ph.net.channels)
         assert reg.gauge("phaser.actors").value == len(rt.ph.actors)
         assert reg.gauge("phaser.epochs").value == len(rt.epochs)
+        ready = reg.gauge("phaser.ready_channels").value
+        assert ready == _CountingFifo.widest
+        assert 0 < ready <= reg.gauge("phaser.channels").value
+        _CountingFifo.widest = 0
     rt.request_demote(keys[-1])
     rt.advance()
     assert reg.counter("phaser.deliveries").value == \
