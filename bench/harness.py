@@ -3,8 +3,10 @@
 A cell names a configuration and a traffic mix in ``BENCHMARK.json``.
 The configuration is ``configs/<config>.json``; the traffic is
 ``traffic/<traffic>.json``, whose ``driver`` names the module under
-``drivers/`` that runs that kind of work; each per-layer metric is
-``metrics/<metric>.py``; the correctness limits of a cell are
+``drivers/`` that runs that kind of work; the configuration's
+``family`` names the module under ``families/`` that holds the model
+block (its parameters, forward pass and counts); each per-layer metric
+is ``metrics/<metric>.py``; the correctness limits of a cell are
 ``limits/<cell>.json``. Adding any of them adds files and entries only.
 """
 from __future__ import annotations
@@ -47,6 +49,26 @@ def load_module(path: str, name: str):
     sys.modules[name] = mod
     spec.loader.exec_module(mod)
     return mod
+
+
+def family_module(name: str, bench_dir: str = BENCH_DIR):
+    """The model family ``families/<name>.py``, loaded once."""
+    mod = sys.modules.get(f"bench_family_{name}")
+    path = os.path.join(bench_dir, "families", f"{name}.py")
+    if mod is None or mod.__file__ != path:
+        mod = load_module(path, f"bench_family_{name}")
+    return mod
+
+
+def family(config: Dict, bench_dir: str = BENCH_DIR):
+    """The family module of a configuration, by its ``family`` key;
+    there is no default."""
+    name = config.get("family")
+    if not name:
+        raise BenchError(f"configuration {config.get('name')!r} states no "
+                         f"\"family\": name its module under "
+                         f"{os.path.join(bench_dir, 'families')}")
+    return family_module(name, bench_dir)
 
 
 @dataclass
@@ -94,7 +116,15 @@ def find_cell(benchmark_path: str, name: str,
         raise BenchError(f"workload {name} names unknown config "
                          f"{w['config']!r}")
     root = os.path.dirname(os.path.abspath(benchmark_path))
-    config = _read_json(os.path.join(root, configs[w["config"]]["file"]))
+    config_path = os.path.join(root, configs[w["config"]]["file"])
+    config = _read_json(config_path)
+    if not config.get("family"):
+        raise BenchError(f"{config_path} states no \"family\"")
+    family_path = os.path.join(bench_dir, "families",
+                               f"{config['family']}.py")
+    if not os.path.isfile(family_path):
+        raise BenchError(f"{config_path} names family "
+                         f"{config['family']!r}: missing file {family_path}")
     traffic = _read_json(os.path.join(bench_dir, "traffic",
                                       f"{w['traffic']}.json"))
     limits = _read_json(os.path.join(bench_dir, "limits", f"{name}.json"))

@@ -1,16 +1,18 @@
-"""Plain float32 reference of the dense decoder the cells run, and the
-seeded weights both sides use.
+"""Plain float32 reference of the models the cells run, and the seeded
+weights both sides use.
 
-Nothing here imports the program. The model is the Llama-style block
-that SmolLM and Granite share (RMSNorm, rotary positions with the
-half-split rotation, grouped-query causal attention, SwiGLU MLP, tied
-embedding), written in straightforward ``jax.numpy`` at float32 and the
-highest matmul precision. Weights are made from the seed by
-``make_weights`` in the program's parameter layout, stored in the dtype
-the configuration states; the reference upcasts them to float32 as it
-reads them. ``quant="fp8"`` rounds every matmul operand to float8 e4m3
-with a per-tensor scale: the control, one precision step below the
-configuration's bfloat16.
+Nothing here imports the program. What depends on the block lives in
+the configuration's family module, ``bench/families/<family>.py``
+(``harness.family``): its parameter shapes and the init rule of each
+leaf, and its forward pass. This module keeps what every family shares:
+the seeded weights, made from the family's shapes in the program's
+layout and stored in the dtype the configuration states, the loss and
+gradient in blocks of rows, AdamW as the train cells run it, and the
+gap of each served token. The reference is straightforward
+``jax.numpy`` at float32 and the highest matmul precision; it upcasts
+the stored weights as it reads them. ``quant="fp8"`` rounds every
+matmul operand to float8 e4m3 with a per-tensor scale: the control, one
+precision step below the configuration's bfloat16.
 """
 from __future__ import annotations
 
@@ -22,37 +24,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench.harness import family, family_module
+
 HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def model_sizes(config: Dict) -> Dict:
-    """The sizes of a configuration file under short names."""
-    D = config["hidden_size"]
-    H = config["num_attention_heads"]
-    return {"L": config["num_hidden_layers"], "D": D, "H": H,
-            "KV": config["num_key_value_heads"],
-            "hd": config.get("head_dim") or D // H,
-            "F": config["intermediate_size"], "V": config["vocab_size"],
-            "theta": float(config["rope_theta"]),
-            "eps": float(config["rms_norm_eps"]),
-            "tied": bool(config["tie_word_embeddings"])}
-
-
 def param_shapes(config: Dict) -> Dict:
-    """Shapes in the program's layout: per-layer leaves stacked on a
-    leading layer axis, projections stored (in, out)."""
-    s = model_sizes(config)
-    L, D, F, V = s["L"], s["D"], s["F"], s["V"]
-    q, kv = s["H"] * s["hd"], s["KV"] * s["hd"]
-    shapes = {"embed": (V, D), "final_norm": (D,),
-              "blocks": {"ln1": (L, D), "ln2": (L, D),
-                         "attn": {"wq": (L, D, q), "wk": (L, D, kv),
-                                  "wv": (L, D, kv), "wo": (L, q, D)},
-                         "mlp": {"gate": (L, D, F), "up": (L, D, F),
-                                 "down": (L, F, D)}}}
-    if not s["tied"]:
-        shapes["lm_head"] = (V, D)
-    return shapes
+    """The family's parameter shapes, in the program's layout."""
+    return family(config).param_shapes(config)
 
 
 def _is_shape(x) -> bool:
@@ -66,16 +45,6 @@ def seed_key(seed: int) -> jax.Array:
         raise ValueError(f"seed {seed} is negative")
     k = jax.random.fold_in(jax.random.key(0), seed & 0x7FFFFFFF)
     return jax.random.fold_in(k, seed >> 31)
-
-
-def _init_leaf(key, path: str, shape, dtype):
-    name = path.rsplit("/", 1)[-1]
-    if name in ("ln1", "ln2", "final_norm"):
-        return jnp.ones(shape, dtype)
-    x = jax.random.normal(key, shape, jnp.float32)
-    # embedding rows at 0.02; projections at 1/sqrt(fan-in)
-    scale = 0.02 if name in ("embed", "lm_head") else 1 / math.sqrt(shape[-2])
-    return (x * scale).astype(dtype)
 
 
 def _paths(shapes, prefix=""):
@@ -98,21 +67,26 @@ def _nest(flat: Dict[str, jax.Array]) -> Dict:
     return tree
 
 
-@functools.partial(jax.jit, static_argnames=("shape_items", "dtype"))
-def _make(key, shape_items, dtype):
-    return {p: _init_leaf(jax.random.fold_in(key, i), p, s, dtype)
+@functools.partial(jax.jit, static_argnames=("fam", "shape_items",
+                                             "dtype"))
+def _make(key, fam, shape_items, dtype):
+    init = family_module(fam).init_leaf
+    return {p: init(jax.random.fold_in(key, i), p, s, dtype)
             for i, (p, s) in enumerate(shape_items)}
 
 
 def make_weights(config: Dict, seed: int, dtype=jnp.bfloat16,
                  device=None) -> Dict:
     """All weights of ``config`` from ``seed``, in one jitted call on the
-    device, in the program's layout."""
+    device, in the program's layout: leaf ``i`` of the sorted paths
+    draws from the seed's key folded with ``i``, by the family's init
+    rule for that leaf."""
     items = tuple(_paths(param_shapes(config)))
     key = seed_key(seed)
     if device is not None:
         key = jax.device_put(key, device)
-    return _nest(_make(key, items, jnp.dtype(dtype).name))
+    return _nest(_make(key, config["family"], items,
+                       jnp.dtype(dtype).name))
 
 
 def flat_leaves(tree) -> Dict[str, jax.Array]:
@@ -140,7 +114,10 @@ def _fp8(x: jax.Array) -> jax.Array:
     return x + jax.lax.stop_gradient(q - x)
 
 
-def _ein(quant: Optional[str]):
+def ein_for(quant: Optional[str]):
+    """The reference's einsum: float32 at the highest precision, or with
+    both operands rounded to float8 first (``quant="fp8"``, the
+    control). Every matmul of a family's forward goes through it."""
     qz = _fp8 if quant == "fp8" else (lambda x: x)
 
     def ein(spec, a, b):
@@ -149,65 +126,11 @@ def _ein(quant: Optional[str]):
     return ein
 
 
-def _rms(x, w, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
-
-
-def _rope(x, pos, theta):
-    """x (B, S, H, hd); the two halves of each head rotate together."""
-    hd = x.shape[-1]
-    inv = 1.0 / theta ** (np.arange(0, hd, 2, dtype=np.float32) / hd)
-    ang = pos[..., None].astype(jnp.float32) * inv          # (B, S, hd/2)
-    c, s = jnp.cos(ang)[:, :, None], jnp.sin(ang)[:, :, None]
-    x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
-    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], -1)
-
-
-def _layer(h, lp, pos, sz, ein):
-    f32 = lambda a: a.astype(jnp.float32)
-    B, S, D = h.shape
-    H, KV, hd = sz["H"], sz["KV"], sz["hd"]
-    x = _rms(h, f32(lp["ln1"]), sz["eps"])
-    q = ein("bsd,de->bse", x, f32(lp["wq"])).reshape(B, S, H, hd)
-    k = ein("bsd,de->bse", x, f32(lp["wk"])).reshape(B, S, KV, hd)
-    v = ein("bsd,de->bse", x, f32(lp["wv"])).reshape(B, S, KV, hd)
-    q, k = _rope(q, pos, sz["theta"]), _rope(k, pos, sz["theta"])
-    k = jnp.repeat(k, H // KV, axis=2)
-    v = jnp.repeat(v, H // KV, axis=2)
-    sc = ein("bqhe,bkhe->bhqk", q, k) / math.sqrt(hd)
-    causal = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
-    sc = jnp.where(causal, sc, -jnp.inf)
-    w = jax.nn.softmax(sc, axis=-1)
-    o = ein("bhqk,bkhe->bqhe", w, v).reshape(B, S, H * hd)
-    h = h + ein("bse,ed->bsd", o, f32(lp["wo"]))
-    x = _rms(h, f32(lp["ln2"]), sz["eps"])
-    g = ein("bsd,df->bsf", x, f32(lp["gate"]))
-    u = ein("bsd,df->bsf", x, f32(lp["up"]))
-    return h + ein("bsf,fd->bsd", jax.nn.silu(g) * u, f32(lp["down"]))
-
-
 def logits(config: Dict, params: Dict, tokens: jax.Array,
            quant: Optional[str] = None) -> jax.Array:
-    """(B, S) tokens -> (B, S, V) float32 logits; layer by layer, each
-    layer recomputed in the backward pass rather than kept."""
-    sz = model_sizes(config)
-    ein = _ein(quant)
-    B, S = tokens.shape
-    pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    emb = params["embed"].astype(jnp.float32)
-    h = emb[tokens]
-    blocks = params["blocks"]
-    per_layer = {"ln1": blocks["ln1"], "ln2": blocks["ln2"],
-                 **blocks["attn"], **blocks["mlp"]}
-
-    @jax.checkpoint
-    def body(h, lp):
-        return _layer(h, lp, pos, sz, ein), None
-
-    h, _ = jax.lax.scan(body, h, per_layer)
-    h = _rms(h, params["final_norm"].astype(jnp.float32), sz["eps"])
-    head = params.get("lm_head", params["embed"]).astype(jnp.float32)
-    return ein("bsd,vd->bsv", h, head)
+    """(B, S) tokens -> (B, S, V) float32 logits by the family's
+    forward."""
+    return family(config).logits(config, params, tokens, quant)
 
 
 def xent(lg: jax.Array, targets: jax.Array) -> jax.Array:
@@ -233,27 +156,49 @@ def _block_grad(params, tokens, targets, cfg_items, quant):
             tokens, targets, config, quant)
 
 
+def _frozen(v):
+    if isinstance(v, dict):
+        return tuple(sorted((k, _frozen(x)) for k, x in v.items()))
+    if isinstance(v, list):
+        return tuple(_frozen(x) for x in v)
+    return v
+
+
 def _cfg_items(config: Dict):
-    keys = ("num_hidden_layers", "hidden_size", "num_attention_heads",
-            "num_key_value_heads", "head_dim", "intermediate_size",
-            "vocab_size", "rope_theta", "rms_norm_eps",
-            "tie_word_embeddings")
-    return tuple((k, config[k]) for k in keys if k in config)
+    """The configuration as a static argument of the reference's jitted
+    calls: its keys and values, lists and nested groups as tuples."""
+    return tuple(sorted((k, _frozen(v)) for k, v in config.items()))
 
 
 def loss_and_grad(config: Dict, params: Dict, tokens: np.ndarray,
                   targets: np.ndarray, *, block_rows: int,
                   rows: Optional[Sequence[int]] = None,
-                  quant: Optional[str] = None):
+                  quant: Optional[str] = None,
+                  devices: Optional[Sequence] = None):
     """Mean loss and float32 gradient over ``rows`` of the batch (all
-    rows by default), ``block_rows`` rows at a time."""
+    rows by default), ``block_rows`` rows at a time. With ``devices``
+    the blocks take turns on them and are summed on the first, in the
+    same order as on one device."""
     rows = list(range(tokens.shape[0])) if rows is None else list(rows)
+    blocks = [rows[i:i + block_rows] for i in range(0, len(rows),
+                                                    block_rows)]
+    if devices:
+        copies = [jax.device_put(params, d) for d in devices]
+        parts = []
+        for j, r in enumerate(blocks):
+            d = j % len(devices)
+            parts.append(_block_grad(
+                copies[d], jax.device_put(tokens[r], devices[d]),
+                jax.device_put(targets[r], devices[d]),
+                _cfg_items(config), quant))
+        parts = jax.device_put(parts, devices[0])
+        del copies
+    else:
+        parts = (_block_grad(params, jnp.asarray(tokens[r]),
+                             jnp.asarray(targets[r]), _cfg_items(config),
+                             quant) for r in blocks)
     total, grad = 0.0, None
-    for i in range(0, len(rows), block_rows):
-        r = rows[i:i + block_rows]
-        l, g = _block_grad(params, jnp.asarray(tokens[r]),
-                           jnp.asarray(targets[r]), _cfg_items(config),
-                           quant)
+    for l, g in parts:
         total = total + l
         grad = g if grad is None else jax.tree_util.tree_map(jnp.add,
                                                               grad, g)
@@ -300,7 +245,8 @@ def _adamw(params, grads, mu, nu, step, lr, *, b1, b2, eps, wd, clip,
 def train_readings(config: Dict, opt: Dict, params0: Dict,
                    batches: Sequence[Dict[str, np.ndarray]], *,
                    block_rows: int, quant: Optional[str] = None,
-                   rows: Optional[Sequence[int]] = None) -> Dict:
+                   rows: Optional[Sequence[int]] = None,
+                   devices: Optional[Sequence] = None) -> Dict:
     """Drive the reference through ``len(batches)`` AdamW steps from
     ``params0``. Returns each step's loss, the first step's clipped
     gradient per leaf and each leaf's change over all the steps, the
@@ -315,7 +261,7 @@ def train_readings(config: Dict, opt: Dict, params0: Dict,
     for i, b in enumerate(batches):
         loss, g = loss_and_grad(config, params, b["tokens"], b["targets"],
                                 block_rows=block_rows, quant=quant,
-                                rows=rows)
+                                rows=rows, devices=devices)
         step = i + 1
         params, clipped, mu, nu = _adamw(
             params, g, mu, nu, jnp.float32(step),

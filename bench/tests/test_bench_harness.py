@@ -101,11 +101,13 @@ def run(ctx):
 def test_a_new_cell_config_and_metric_are_found_by_name(tmp_path):
     """Everything a later change adds is files plus entries."""
     bench = tmp_path / "bench"
-    for d in ("configs", "traffic", "limits", "drivers", "metrics"):
+    for d in ("configs", "traffic", "limits", "drivers", "metrics",
+              "families"):
         (bench / d).mkdir(parents=True)
     shutil.copy(os.path.join(ROOT, "bench", "peaks.json"), bench)
     (bench / "configs" / "dummy.json").write_text(json.dumps(
-        {"name": "dummy"}))
+        {"name": "dummy", "family": "dummy"}))
+    (bench / "families" / "dummy.py").write_text('"""No model."""\n')
     (bench / "traffic" / "tiny.json").write_text(json.dumps(
         {"driver": "dummy", "n": 21}))
     (bench / "limits" / "dummy.tiny.json").write_text(json.dumps(

@@ -6,8 +6,9 @@ module events of each device plane and the harness's own host spans,
 all on the profiler's clock in nanoseconds. ``reduce`` computes from
 that record what the per-layer metrics read: busy and idle time, device
 time per module and per op, and the idle gaps named by the host span
-that covered them. The reduction is plain Python over lists,
-so a test can run it on a small recorded trace.
+that covered them, and the device time of the gradient sync's ops and
+the part of it that no other op covers. The reduction is plain Python
+over lists, so a test can run it on a small recorded trace.
 """
 from __future__ import annotations
 
@@ -23,6 +24,16 @@ MODULES_LINE = "XLA Modules"
 # host spans the harness writes with jax.profiler.TraceAnnotation
 HOST_SPANS = ("window", "data", "dispatch", "submit", "engine.step",
               "readback", "idle")
+# device ops of the gradient sync, by label, matched on the op's group
+# (``op_group``): the rounds' point-to-point transfers; the Pallas
+# bucket combine, a ``tpu_custom_call`` that XLA names after the
+# ``shard_map`` it sits in; XLA's all-reduce (a ``psum``)
+SYNC_OPS = {"permute": r"^(collective-permute|ppermute)",
+            "bucket_combine": r"^shard_map$",
+            "all_reduce": r"^all-reduce"}
+# ops that hold other ops on the trace's op line and do no work of
+# their own beside them
+CONTAINER_OPS = r"^(while|conditional|call)$"
 
 
 class Capture:
@@ -166,18 +177,20 @@ def reduce(rec: Dict, window: Optional[Interval] = None,
     module executions whose name matches it count towards that label's
     ``module_matched`` time and count."""
     mpats = {k: re.compile(v) for k, v in (module_match or {}).items()}
+    spats = {k: re.compile(v) for k, v in SYNC_OPS.items()}
+    container = re.compile(CONTAINER_OPS)
     window = window or window_of(rec)
     if window is None:
         raise ValueError("the trace holds no window span")
     lo, hi = window
     per_dev = []
     for dev_id, dev in sorted(rec["devices"].items()):
-        segs = [(op_group(n), clip([(s, s + d)], lo, hi))
+        segs = [(op_group(n), clip([(s, s + d)], lo, hi), d)
                 for n, s, d in dev["ops"]]
-        segs = [(g, seg[0]) for g, seg in segs if seg]
-        busy = union(seg for _, seg in segs)
+        segs = [(g, seg[0], d) for g, seg, d in segs if seg]
+        busy = union(seg for _, seg, _ in segs)
         by_op: Dict[str, float] = {}
-        for g, (a, b) in segs:
+        for g, (a, b), _ in segs:
             by_op[g] = by_op.get(g, 0.0) + (b - a)
         modules: Dict[str, Dict[str, float]] = {}
         for n, s, d in dev["modules"]:
@@ -187,7 +200,8 @@ def reduce(rec: Dict, window: Optional[Interval] = None,
                 m["ns"] += length(seg)
                 m["count"] += 1
         per_dev.append({"id": dev_id, "busy_ns": length(busy),
-                        "busy": busy, "by_op": by_op, "modules": modules})
+                        "busy": busy, "by_op": by_op, "modules": modules,
+                        "sync": sync_time(segs, spats, container)})
     if not per_dev:
         raise ValueError("the trace holds no TPU device plane")
     n = len(per_dev)
@@ -201,6 +215,11 @@ def reduce(rec: Dict, window: Optional[Interval] = None,
             o["count"] += m["count"] / n
         for g, t in d["by_op"].items():
             out["by_op"][g] = out["by_op"].get(g, 0.0) + t / n
+    out["sync"] = {
+        "ns": sum(d["sync"]["ns"] for d in per_dev) / n,
+        "exposed_ns": sum(d["sync"]["exposed_ns"] for d in per_dev) / n,
+        "ops": {k: {f: sum(d["sync"]["ops"][k][f] for d in per_dev) / n
+                    for f in ("ns", "count")} for k in spats}}
     out["module_matched"] = {
         k: {"ns": sum(m["ns"] for n, m in out["modules"].items()
                       if p.search(n)),
@@ -209,6 +228,28 @@ def reduce(rec: Dict, window: Optional[Interval] = None,
         for k, p in mpats.items()}
     out["idle_gaps"] = idle_by_host(per_dev[0]["busy"], rec["host"], lo, hi)
     return out
+
+
+def sync_time(segs, spats, container) -> Dict:
+    """One device's gradient sync from its ops ``(group, clipped
+    interval, full duration)``: the time under any sync op, the part of
+    it that no other op covers (``exposed_ns``; ops that only hold
+    others cover nothing), and each label's op time and launches, a
+    launch the window cuts counted by the share of it inside."""
+    ops = {k: {"ns": 0.0, "count": 0.0} for k in spats}
+    sync, other = [], []
+    for g, seg, dur in segs:
+        label = next((k for k, p in spats.items() if p.search(g)), None)
+        if label is not None:
+            ops[label]["ns"] += seg[1] - seg[0]
+            ops[label]["count"] += (seg[1] - seg[0]) / dur
+            sync.append(seg)
+        elif not container.search(g):
+            other.append(seg)
+    sync = union(sync)
+    t = length(sync)
+    return {"ns": t, "exposed_ns": t - length(intersect(sync, union(other))),
+            "ops": ops}
 
 
 def idle_by_host(busy: Sequence[Interval], host: Sequence, lo: float,
